@@ -50,11 +50,6 @@ from repro.decode.scheduler import Lane, PagedArmScheduler
 from repro.engine.types import next_pow2
 from repro.obs import Histogram, annotation, get_tracer
 
-try:
-    from jax.experimental.shard_map import shard_map
-except ImportError:                                    # newer jax: jax.shard_map
-    from jax import shard_map                          # pragma: no cover
-
 
 @dataclass
 class Shipment:
@@ -538,8 +533,9 @@ class CacheStore:
             return scatter_blocks(pool, payload, d)
 
         def ship(stacked, sids, dids):
-            return shard_map(body, mesh=mesh, in_specs=(specs, P(), P()),
-                             out_specs=specs)(stacked, sids, dids)
+            return jax.shard_map(body, mesh=mesh,
+                                 in_specs=(specs, P(), P()),
+                                 out_specs=specs)(stacked, sids, dids)
 
         return ship
 

@@ -228,6 +228,30 @@ def _stack_body(cfg: ArchConfig, h, sb_params, sb_pool, attn_fn):
     return h, new_sb_pool
 
 
+def _scan_stack(cfg: ArchConfig, params, pool, x, attn_fn):
+    """Scan the superblock stack with the pool in the loop carry: each
+    superblock reads its pool entry and writes it back in place
+    (``dynamic_update_index_in_dim``).  Passing the pool as scanned
+    inputs/outputs instead makes XLA hold a second full pool for the
+    stacked outputs, which at published widths does not fit one chip."""
+    def body(carry, xs):
+        h, pool = carry
+        sb_params, i = xs
+        sb_pool = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+            pool)
+        h, new_sb_pool = _stack_body(cfg, h, sb_params, sb_pool, attn_fn)
+        pool = jax.tree.map(
+            lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, i, 0),
+            pool, new_sb_pool)
+        return (h, pool), None
+
+    n_sb = jax.tree.leaves(pool)[0].shape[0]
+    (x, pool), _ = jax.lax.scan(body, (x, pool),
+                                (params["blocks"], jnp.arange(n_sb)))
+    return x, pool
+
+
 def _paged_step_one(model: Model, params, pool, tokens, block_tables,
                     lengths, active, *, interpret: bool):
     """Single-branch paged decode step.  tokens: [B, 1]; lengths: [B] tokens
@@ -241,15 +265,11 @@ def _paged_step_one(model: Model, params, pool, tokens, block_tables,
     wb, wo = write_slots(lengths, block_tables, active, block_size)
     valid_lens = lengths + active.astype(jnp.int32)
 
-    def body(h, xs):
-        sb_params, sb_pool = xs
-        attn = lambda p, hn, entry: _paged_attn(
-            p, hn, cfg, positions=positions, pool=entry,
-            block_tables=block_tables, valid_lens=valid_lens, wb=wb, wo=wo,
-            interpret=interpret)
-        return _stack_body(cfg, h, sb_params, sb_pool, attn)
-
-    x, new_pool = jax.lax.scan(body, x, (params["blocks"], pool))
+    attn = lambda p, hn, entry: _paged_attn(
+        p, hn, cfg, positions=positions, pool=entry,
+        block_tables=block_tables, valid_lens=valid_lens, wb=wb, wo=wo,
+        interpret=interpret)
+    x, new_pool = _scan_stack(cfg, params, pool, x, attn)
     x = L.norm_apply(params["final_norm"], x, cfg)
     logits = L.unembed_apply(params["embed"], x, cfg)
     return logits[:, -1], new_pool
@@ -268,14 +288,10 @@ def _paged_chunk_one(model: Model, params, pool, tokens, starts, n_tok,
     positions = starts[:, None] + jnp.arange(c)[None, :]
     wb, wo = chunk_write_slots(starts, n_tok, block_tables, block_size, c)
 
-    def body(h, xs):
-        sb_params, sb_pool = xs
-        attn = lambda p, hn, entry: _paged_chunk_attn(
-            p, hn, cfg, positions=positions, pool=entry,
-            block_tables=block_tables, wb=wb, wo=wo, interpret=interpret)
-        return _stack_body(cfg, h, sb_params, sb_pool, attn)
-
-    x, new_pool = jax.lax.scan(body, x, (params["blocks"], pool))
+    attn = lambda p, hn, entry: _paged_chunk_attn(
+        p, hn, cfg, positions=positions, pool=entry,
+        block_tables=block_tables, wb=wb, wo=wo, interpret=interpret)
+    x, new_pool = _scan_stack(cfg, params, pool, x, attn)
     x = L.norm_apply(params["final_norm"], x, cfg)
     idx = jnp.clip(n_tok - 1, 0, c - 1)[:, None, None]
     x = jnp.take_along_axis(x, jnp.broadcast_to(

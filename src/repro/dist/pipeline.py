@@ -50,7 +50,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist import sharding as SH
@@ -405,9 +404,9 @@ def stage_graph_loss(model, params, batch, mesh, *, schedule: str = "gpipe",
     tables = (jnp.asarray(sched.f_mb), jnp.asarray(sched.f_read),
               jnp.asarray(sched.f_wslot))
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(p_specs, P(None, "data"), P(None, "data")),
-             out_specs=P(), check_rep=False)
+             out_specs=P(), check_vma=False)
     def run(params, mt, ml):
         col = jax.lax.axis_index("model")
         fwd_buf = _stack_zero(_payload_zero(cfg, b_local, seq),
@@ -464,9 +463,9 @@ def stage_graph_value_and_grad(model, params, batch, mesh, *,
         sched.f_mb, sched.f_read, sched.f_save, sched.f_wslot,
         sched.b_mb, sched.b_slot, sched.b_read, sched.b_wslot))
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(p_specs, P(None, "data"), P(None, "data")),
-             out_specs=(P(), p_specs), check_rep=False)
+             out_specs=(P(), p_specs), check_vma=False)
     def run(params, mt, ml):
         col = jax.lax.axis_index("model")
         zero_payload = _payload_zero(cfg, b_local, seq)
@@ -560,8 +559,8 @@ def ep_loss(model, params, batch, mesh, *, n_micro: int = 1,
     over 'model'; the batch is sharded over 'data'."""
     p_specs, b_specs = _ep_specs(model, params, batch, mesh, n_micro)
 
-    @partial(shard_map, mesh=mesh, in_specs=(p_specs, b_specs),
-             out_specs=P(), check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(p_specs, b_specs),
+             out_specs=P(), check_vma=False)
     def run(params, batch):
         loss = microbatch_loss(model, params, batch, n_micro, remat=remat)
         return jax.lax.pmean(loss, "data")
@@ -583,8 +582,8 @@ def ep_value_and_grad(model, params, batch, mesh, *, n_micro: int = 1,
     p_specs, b_specs = _ep_specs(model, params, batch, mesh, n_micro)
     n_model = _mesh_sizes(mesh).get("model", 1)
 
-    @partial(shard_map, mesh=mesh, in_specs=(p_specs, b_specs),
-             out_specs=(P(), p_specs), check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(p_specs, b_specs),
+             out_specs=(P(), p_specs), check_vma=False)
     def run(params, batch):
         loss, grads = jax.value_and_grad(
             lambda p: microbatch_loss(model, p, batch, n_micro,

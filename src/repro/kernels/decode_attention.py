@@ -16,21 +16,23 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, block_l: int,
                    L: int, scale: float, softcap: float):
-    # q_ref: [rep, hd]; k_ref/v_ref: [L, hd]; o_ref: [rep, hd]
+    # len_ref: [B] (SMEM, scalar prefetch); q_ref: [rep, hd];
+    # k_ref/v_ref: [L, hd]; o_ref: [rep, hd]
     rep, hd = q_ref.shape
     q = q_ref[...].astype(jnp.float32) * scale
-    valid_len = len_ref[0]
+    valid_len = len_ref[pl.program_id(0)]
 
     def body(i, carry):
         acc, m_prev, l_prev = carry
-        k = pl.load(k_ref, (pl.dslice(i * block_l, block_l), slice(None)))
-        v = pl.load(v_ref, (pl.dslice(i * block_l, block_l), slice(None)))
+        k = k_ref[pl.ds(i * block_l, block_l), :]
+        v = v_ref[pl.ds(i * block_l, block_l), :]
         s = q @ k.astype(jnp.float32).T                     # [rep, bl]
         if softcap:
             s = jnp.tanh(s / softcap) * softcap
@@ -73,15 +75,20 @@ def decode_attention(q, k_cache, v_cache, length, *, block_l: int = 256,
                                scale=scale, softcap=softcap)
     out = pl.pallas_call(
         kernel,
-        grid=(b, kh),
-        in_specs=[
-            pl.BlockSpec((1,), lambda bi, ki: (bi,)),
-            pl.BlockSpec((None, None, rep, hd), lambda bi, ki: (bi, ki, 0, 0)),
-            pl.BlockSpec((None, None, L, hd), lambda bi, ki: (bi, ki, 0, 0)),
-            pl.BlockSpec((None, None, L, hd), lambda bi, ki: (bi, ki, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, None, rep, hd),
-                               lambda bi, ki: (bi, ki, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, kh),
+            in_specs=[
+                pl.BlockSpec((None, None, rep, hd),
+                             lambda bi, ki, _: (bi, ki, 0, 0)),
+                pl.BlockSpec((None, None, L, hd),
+                             lambda bi, ki, _: (bi, ki, 0, 0)),
+                pl.BlockSpec((None, None, L, hd),
+                             lambda bi, ki, _: (bi, ki, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((None, None, rep, hd),
+                                   lambda bi, ki, _: (bi, ki, 0, 0)),
+        ),
         out_shape=jax.ShapeDtypeStruct((b, kh, rep, hd), q.dtype),
         interpret=interpret,
     )(length.astype(jnp.int32), qg, kt, vt)

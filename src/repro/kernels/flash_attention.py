@@ -35,8 +35,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, sk: int,
 
     def body(i, carry):
         acc, m_prev, l_prev = carry
-        k = pl.load(k_ref, (pl.dslice(i * block_k, block_k), slice(None)))
-        v = pl.load(v_ref, (pl.dslice(i * block_k, block_k), slice(None)))
+        k = k_ref[pl.ds(i * block_k, block_k), :]
+        v = v_ref[pl.ds(i * block_k, block_k), :]
         s = q @ k.astype(jnp.float32).T                     # [bq, bk]
         if softcap:
             s = jnp.tanh(s / softcap) * softcap

@@ -20,8 +20,9 @@ def _gmm_kernel(x_ref, w_ref, o_ref, *, block_d: int, d: int):
     @functools.partial(jax.lax.fori_loop, 0, d // block_d,
                        init_val=jnp.zeros(o_ref.shape, jnp.float32))
     def acc(i, acc):
-        xs = pl.load(x_ref, (slice(None), pl.dslice(i * block_d, block_d)))
-        ws = pl.load(w_ref, (pl.dslice(i * block_d, block_d), slice(None)))
+        off = pl.multiple_of(i * block_d, block_d)
+        xs = x_ref[:, pl.ds(off, block_d)]
+        ws = w_ref[pl.ds(off, block_d), :]
         return acc + xs.astype(jnp.float32) @ ws.astype(jnp.float32)
     o_ref[...] = acc.astype(o_ref.dtype)
 

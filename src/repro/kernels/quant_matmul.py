@@ -112,15 +112,17 @@ def _qmm_kernel(x_ref, q_ref, s_ref, o_ref, *, bits: int, group: int,
     half = group // 2
 
     def body(g, acc):
-        xg = pl.load(x_ref, (slice(None), pl.dslice(g * group, group))) \
+        xg = x_ref[:, pl.ds(pl.multiple_of(g * group, group), group)] \
             .astype(jnp.float32)
-        sc = pl.load(s_ref, (pl.dslice(g, 1), slice(None)))  # [1, be]
+        sc = s_ref[pl.ds(g, 1), :]                            # [1, be]
         if bits == 8:
-            wq = pl.load(q_ref, (pl.dslice(g * group, group), slice(None)))
+            wq = q_ref[pl.ds(pl.multiple_of(g * group, group), group), :]
             part = xg @ wq.astype(jnp.float32)
         else:
-            p = pl.load(q_ref, (pl.dslice(g * half, half), slice(None)))
-            lo = ((p << 4) >> 4).astype(jnp.float32)
+            # widen before shifting: the TPU vector unit has no int8 shifts
+            p = q_ref[pl.ds(pl.multiple_of(g * half, half), half), :] \
+                .astype(jnp.int32)
+            lo = ((p << 28) >> 28).astype(jnp.float32)
             hi = (p >> 4).astype(jnp.float32)
             part = xg[:, :half] @ lo + xg[:, half:] @ hi
         return acc + part * sc

@@ -128,8 +128,6 @@ def run_dryrun(arch: str, shape_name: str, *, mode: str = None,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older jax: one dict per module
-        cost = cost[0] if cost else {}
     n_dev = mesh.devices.size
     record = {
         "arch": arch, "shape": shape_name, "mode": mode, "variant": variant,

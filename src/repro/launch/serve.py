@@ -2,7 +2,9 @@
 and mesh (MAB policy + JaxBackend with EDF continuous batching).
 
     PYTHONPATH=src python -m repro.launch.serve --arch stablelm-1.6b \
-        --batches 8 --reduced
+        --batches 8                              # reduced widths, float32
+    PYTHONPATH=src python -m repro.launch.serve --no-reduced \
+        --dtype bfloat16 --cache-len 1024        # published widths
 
 For pod-scale layout experiments use launch/dryrun.py (AOT, no allocation);
 this driver executes real steps on the available devices.
@@ -12,11 +14,22 @@ from __future__ import annotations
 import argparse
 import json
 
-import jax
 import numpy as np
 
 from repro.configs.base import get_config
 from repro.engine import JaxBackend, MABPolicy, PlacementEngine, Request
+from repro.launch.cache import enable_compile_cache
+from repro.launch.train import make_mesh
+
+
+def build_engine(cfg, mesh, *, cache_len: int, max_batch: int,
+                 bandit: str = "ucb", **backend_kw) -> PlacementEngine:
+    """The served stack: MAB split policy over a ``JaxBackend`` holding the
+    default split arms; ``backend_kw`` passes through to ``JaxBackend``."""
+    return PlacementEngine(
+        MABPolicy(bandit=bandit, ema_init_values=None, n_ctx=8),
+        JaxBackend(cfg, mesh, cache_len=cache_len, max_batch=max_batch,
+                   **backend_kw))
 
 
 def main(argv=None):
@@ -27,20 +40,22 @@ def main(argv=None):
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--cache-len", type=int, default=64)
     ap.add_argument("--max-batch", type=int, default=8)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="smoke-scale widths (--no-reduced: published)")
+    ap.add_argument("--dtype", choices=["float32", "bfloat16"], default=None,
+                    help="parameter/cache dtype (default: the config's)")
     ap.add_argument("--bandit", default="ucb")
     args = ap.parse_args(argv)
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    dims = tuple(int(x) for x in args.mesh.split(","))
-    mesh = jax.make_mesh(dims, ("data", "model")[:len(dims)] if len(dims) == 2
-                         else ("pod", "data", "model"))
-    eng = PlacementEngine(
-        MABPolicy(bandit=args.bandit, ema_init_values=None, n_ctx=8),
-        JaxBackend(cfg, mesh, cache_len=args.cache_len,
-                   max_batch=args.max_batch))
+    if args.dtype:
+        cfg = cfg.replace(dtype=args.dtype)
+    enable_compile_cache()
+    eng = build_engine(cfg, make_mesh(args.mesh), cache_len=args.cache_len,
+                       max_batch=args.max_batch, bandit=args.bandit)
     rng = np.random.default_rng(0)
     rid = 0
     for b in range(args.batches):

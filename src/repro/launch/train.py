@@ -14,19 +14,23 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.checkpoint import save
 from repro.configs.base import get_config
 from repro.data.pipeline import batches_for
 from repro.dist import api as A
-from repro.optim.adamw import adamw_init, cosine_schedule
+from repro.launch.cache import enable_compile_cache
+from repro.optim.adamw import adamw_init
 
 
 def make_mesh(spec: str):
+    """Mesh from "data,model" or "pod,data,model".  Axes are Auto: the
+    runners place their layouts through shardings, not sharding-in-types."""
     dims = [int(x) for x in spec.split(",")]
     names = ("data", "model") if len(dims) == 2 else ("pod", "data", "model")
-    return jax.make_mesh(tuple(dims), names)
+    return jax.make_mesh(tuple(dims), names,
+                         axis_types=(AxisType.Auto,) * len(dims))
 
 
 def shardings(mesh, specs):
@@ -69,47 +73,61 @@ def main(argv=None):
         cfg = cfg.reduced()
         if args.d_model:
             cfg = cfg.replace(d_model=args.d_model)
+    enable_compile_cache()
+    return train(cfg, make_mesh(args.mesh), mode=args.mode, steps=args.steps,
+                 seq_len=args.seq_len, batch=args.batch, lr=args.lr,
+                 schedule=args.schedule,
+                 n_microbatches=args.n_microbatches,
+                 memory_budget=args.memory_budget,
+                 expert_parallel=args.expert_parallel, ckpt=args.ckpt,
+                 log_every=args.log_every)
+
+
+def train(cfg, mesh, *, mode: str, steps: int, seq_len: int, batch: int,
+          lr: float, schedule: str = "gspmd", n_microbatches: int = 0,
+          memory_budget: int = 0, expert_parallel: bool = False,
+          ckpt: str = "", log_every: int = 10):
+    """Train ``cfg`` (in float32) on ``mesh`` for ``steps`` steps of the
+    seeded synthetic stream; returns the per-step losses.  Parameters and
+    data depend only on the seed, so two runs that differ only in ``mode``
+    or ``schedule`` see the same step-0 parameters and batch."""
     cfg = cfg.replace(dtype="float32")
-
-    mesh = make_mesh(args.mesh)
     runner = A.build_runner(
-        cfg, args.mode, mesh,
-        n_microbatches=args.n_microbatches or None,
-        schedule=args.schedule if args.mode == "pipeline" else "gspmd",
-        memory_budget=args.memory_budget or None,
-        expert_parallel=args.expert_parallel)
+        cfg, mode, mesh,
+        n_microbatches=n_microbatches or None,
+        schedule=schedule if mode == "pipeline" else "gspmd",
+        memory_budget=memory_budget or None,
+        expert_parallel=expert_parallel)
     rcfg = runner.cfg
-    if args.mode == "pipeline":
-        print("schedule:", runner.schedule_stats(args.batch, args.seq_len),
-              flush=True)
+    if mode == "pipeline":
+        print("schedule:", runner.schedule_stats(batch, seq_len), flush=True)
     key = jax.random.PRNGKey(0)
-    params = runner.init(key)
-    opt = adamw_init(params)
-    p_specs = runner.param_specs(params)
+    p_specs = runner.param_specs(jax.eval_shape(runner.init, key))
     p_shard = shardings(mesh, p_specs)
-    params = jax.device_put(params, p_shard)
-
-    sched = cosine_schedule(args.lr, warmup=max(args.steps // 20, 1),
-                            total=args.steps)
-    step_fn = A.make_train_step(runner, lr=args.lr, remat=True)
     o_shard = shardings(mesh, A.make_opt_specs(p_specs))
+    # initialize straight into the shardings: an unsharded init holds the
+    # whole model and optimizer state on the first device
+    params = jax.jit(runner.init, out_shardings=p_shard)(key)
+    opt = jax.jit(adamw_init, out_shardings=o_shard)(params)
+
+    step_fn = A.make_train_step(runner, lr=lr, remat=True)
     jstep = jax.jit(step_fn, in_shardings=(p_shard, o_shard, None),
                     out_shardings=(p_shard, o_shard, None), donate_argnums=(0, 1))
 
-    data = batches_for(rcfg, seq_len=args.seq_len, global_batch=args.batch)
+    data = batches_for(rcfg, seq_len=seq_len, global_batch=batch)
     losses = []
     t0 = time.time()
-    for step in range(args.steps):
-        batch = {k: jnp.asarray(v) for k, v in next(data).items()}
-        params, opt, loss = jstep(params, opt, batch)
+    for step in range(steps):
+        b = {k: jnp.asarray(v) for k, v in next(data).items()}
+        params, opt, loss = jstep(params, opt, b)
         losses.append(float(loss))
-        if step % args.log_every == 0 or step == args.steps - 1:
+        if step % log_every == 0 or step == steps - 1:
             dt = time.time() - t0
             print(f"step {step:5d} loss {losses[-1]:.4f} "
                   f"({dt / (step + 1):.2f}s/step)", flush=True)
-    if args.ckpt:
-        save(f"{args.ckpt}/step_{args.steps}.npz", params, step=args.steps)
-        print(f"checkpoint -> {args.ckpt}/step_{args.steps}.npz")
+    if ckpt:
+        save(f"{ckpt}/step_{steps}.npz", params, step=steps)
+        print(f"checkpoint -> {ckpt}/step_{steps}.npz")
     print(f"first-10 mean {np.mean(losses[:10]):.4f} -> "
           f"last-10 mean {np.mean(losses[-10:]):.4f}")
     return losses

@@ -5,22 +5,6 @@ import os
 # (per the dry-run contract: only dryrun.py forces 512).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-try:
-    import hypothesis  # noqa: F401  — real dependency, preferred when present
-except ModuleNotFoundError:
-    # Offline container: register the vendored deterministic fallback
-    # (tests/_hypothesis_fallback.py) under the ``hypothesis`` name.
-    import importlib.util
-    import pathlib
-    import sys
-
-    _path = pathlib.Path(__file__).with_name("_hypothesis_fallback.py")
-    _spec = importlib.util.spec_from_file_location("hypothesis", _path)
-    _mod = importlib.util.module_from_spec(_spec)
-    sys.modules["hypothesis"] = _mod
-    _spec.loader.exec_module(_mod)
-    sys.modules["hypothesis.strategies"] = _mod.strategies
-
 import numpy as np
 import pytest
 

@@ -119,3 +119,27 @@ def test_semantic_specs_have_branch_axis():
     specs = SH.semantic_param_specs(params, FakeMesh())
     for spec in jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P)):
         assert spec[0] == "model"  # branch dim always over 'model'
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins and no other directory is set in code;
+    without it the cache sits at the fixed in-checkout path."""
+    from repro.launch import cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = str(cache.REPO_ROOT / ".jax_cache")
+            assert cache.enable_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+            assert (cache.REPO_ROOT / "pyproject.toml").exists()
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                               str(tmp_path / env_dir))
+            assert cache.enable_compile_cache() == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir is None
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
