@@ -48,7 +48,7 @@ from repro.decode.paged_cache import (NULL_BLOCK, _is_scale_path,
                                       gather_blocks, scatter_blocks)
 from repro.decode.scheduler import Lane, PagedArmScheduler
 from repro.engine.types import next_pow2
-from repro.obs import Histogram, annotation, get_tracer
+from repro.obs import Histogram, get_tracer
 
 
 @dataclass
@@ -336,7 +336,8 @@ class CacheStore:
         sp.set(shipped=len(wave), blocks=len(flat_src))
         fault = None
         if flat_src:
-            with annotation(f"ship:{next_pow2(len(flat_src))}"):
+            with tr.span("transfer", label=f"ship:{next_pow2(len(flat_src))}",
+                         blocks=len(flat_src)):
                 self._transfer(flat_src, flat_dst)
             self.blocks_shipped += len(flat_src)
             self.transfer_bytes += len(flat_src) * self.src.kv_block_bytes

@@ -32,6 +32,7 @@ can see recompile churn (``compile_stats``).
 from __future__ import annotations
 
 import heapq
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
@@ -46,7 +47,7 @@ from repro.decode.paged_model import (make_decode_fn, make_prefill_chunk_fn,
                                       quantize_attn_params,
                                       supports_paged_decode)
 from repro.engine.types import next_pow2
-from repro.obs import Histogram, annotation, get_tracer
+from repro.obs import Histogram, get_tracer
 
 
 @dataclass
@@ -61,6 +62,8 @@ class Lane:
     preemptions: int = 0
     committed: int = 0           # cache slots filled when detached for ship
     first_tok_t: float = 0.0     # wall-clock of the first generated token
+    seat_t: float = 0.0          # host clock at this seating
+    prefill_busy_s: float = 0.0  # chunk waves run for it since seating
 
     @property
     def deadline(self) -> float:
@@ -121,6 +124,8 @@ class PagedArmScheduler:
         self.role = role
         self.device = device
         self.clock = clock
+        # the prefill counters' host clock: the owner's, else perf_counter
+        self._clock = clock if clock is not None else time.perf_counter
         # trace track: (process, thread) labels for this scheduler's span
         # row in the Chrome trace; JaxBackend overwrites with arm labels
         dev = device if device is not None else jax.devices()[0]
@@ -193,6 +198,11 @@ class PagedArmScheduler:
         self._active_frac_sum = 0.0   # running mean, not an unbounded list
         self.prefix_hit_tokens = 0
         self.prefix_query_tokens = 0
+        # per lane that took its first token: seat -> first token, and the
+        # chunk waves that carried it (dispatch through readback); their
+        # difference is the time its prefill stalled between chunks
+        self.prefill_wait_lane_s = 0.0
+        self.prefill_busy_lane_s = 0.0
         self.cow_copies = 0
         self.preemptions = 0
         self.spilled_blocks = 0
@@ -431,6 +441,7 @@ class PagedArmScheduler:
 
     def _join_wave(self, queue: list, now: float, free: List[int]) -> int:
         tr = get_tracer()
+        t_seat = self._clock()
         seat = iter(free)
         cow_pairs: List[tuple] = []
         admitted = 0
@@ -506,6 +517,8 @@ class PagedArmScheduler:
                 covered += keep
             lane.blocks = shared + ids
             lane.n_shared = len(shared)
+            lane.seat_t = t_seat
+            lane.prefill_busy_s = 0.0
             li = next(seat)
             self.lanes[li] = lane
             row = np.full(self.max_blocks, NULL_BLOCK, np.int32)
@@ -540,8 +553,7 @@ class PagedArmScheduler:
         fn = self._get_jitted("cow", (n_pad,),
                               lambda: copy_blocks, donate=(0,))
         with get_tracer().span("cow_copy", track=self.track,
-                               pairs=len(cow_pairs)), \
-                annotation(f"cow:{n_pad}"):
+                               label=f"cow:{n_pad}", pairs=len(cow_pairs)):
             self.pool = fn(self.pool, jnp.asarray(src), jnp.asarray(dst))
         self.cow_copies += len(cow_pairs)
         # copies done — the pinned sources can go back to the cache
@@ -559,47 +571,57 @@ class PagedArmScheduler:
               if l is not None and self.prefill_left[i] > 0]
         if not pf:
             return []
-        w = next_pow2(len(pf))
-        # chunk length buckets to the widest lane's need (pow2, capped at
-        # prefill_chunk) — prefix-cache hits leave short tails, and an
-        # 8-token tail must not pay a chunk-wide dispatch
-        c = min(self.prefill_chunk,
-                next_pow2(int(min(np.max(self.prefill_left[pf]),
-                                  self.prefill_chunk))))
-        toks = np.zeros((w, c), np.int32)
-        starts = np.zeros(w, np.int32)
-        n_tok = np.zeros(w, np.int32)
-        bt = np.full((w, self.max_blocks), NULL_BLOCK, np.int32)
-        for row, li in enumerate(pf):
-            lane = self.lanes[li]
-            s0 = int(self.lengths[li])
-            k = min(int(self.prefill_left[li]), c)
-            toks[row, :k] = lane.history()[s0:s0 + k]
-            starts[row] = s0
-            n_tok[row] = k
-            bt[row] = self.block_tables[li]
-        fn = self._get_jitted(
-            "prefill", (w, c),
-            lambda: make_prefill_chunk_fn(self.model,
-                                          interpret=self.interpret))
         tr = get_tracer()
-        with tr.span("prefill_chunk", track=self.track, wave=len(pf),
-                     chunk=c), annotation(f"prefill:{w}x{c}"):
+        with tr.span("prefill_prep", track=self.track):
+            w = next_pow2(len(pf))
+            # chunk length buckets to the widest lane's need (pow2, capped
+            # at prefill_chunk) — prefix-cache hits leave short tails, and
+            # an 8-token tail must not pay a chunk-wide dispatch
+            c = min(self.prefill_chunk,
+                    next_pow2(int(min(np.max(self.prefill_left[pf]),
+                                      self.prefill_chunk))))
+            toks = np.zeros((w, c), np.int32)
+            starts = np.zeros(w, np.int32)
+            n_tok = np.zeros(w, np.int32)
+            bt = np.full((w, self.max_blocks), NULL_BLOCK, np.int32)
+            for row, li in enumerate(pf):
+                lane = self.lanes[li]
+                s0 = int(self.lengths[li])
+                k = min(int(self.prefill_left[li]), c)
+                toks[row, :k] = lane.history()[s0:s0 + k]
+                starts[row] = s0
+                n_tok[row] = k
+                bt[row] = self.block_tables[li]
+            fn = self._get_jitted(
+                "prefill", (w, c),
+                lambda: make_prefill_chunk_fn(self.model,
+                                              interpret=self.interpret))
+        rids = [self.lanes[li].req.rid for li in pf] if tr.enabled else None
+        t0 = self._clock()
+        with tr.span("prefill_chunk", track=self.track,
+                     label=f"prefill:{w}x{c}", wave=len(pf), chunk=c,
+                     rids=rids):
             logits, self.pool = fn(self.params, self.pool, jnp.asarray(toks),
                                    jnp.asarray(starts), jnp.asarray(n_tok),
                                    jnp.asarray(bt))
-            first = np.asarray(jnp.argmax(logits, axis=-1)).astype(np.int32)
+            with tr.span("prefill_readback"):
+                first = np.asarray(jnp.argmax(logits, axis=-1)).astype(
+                    np.int32)
+        t1 = self._clock()
         self.prefill_chunks += 1
 
         retired: List[Lane] = []
-        t_first = self.clock() if self.clock is not None else now
+        t_first = t1 if self.clock is not None else now
         for row, li in enumerate(pf):
             lane = self.lanes[li]
+            lane.prefill_busy_s += t1 - t0
             k = min(int(self.prefill_left[li]), c)
             self.lengths[li] += k
             self.prefill_left[li] -= k
             if self.prefill_left[li] > 0:
                 continue
+            self.prefill_wait_lane_s += t1 - lane.seat_t
+            self.prefill_busy_lane_s += lane.prefill_busy_s
             lane.out.append(int(first[row]))
             lane.first_tok_t = t_first
             tr.instant("first_token", track=self.track, req=lane.req.rid)
@@ -705,26 +727,30 @@ class PagedArmScheduler:
         n_act = len(act)
         if n_act == 0:
             return None
-        w = next_pow2(n_act)
-        k_eff = self._scan_bucket(self.remaining[act])
-        fn = self._get_jitted(
-            "decode", (w, k_eff),
-            lambda: make_decode_fn(self.model, scan_tokens=k_eff,
-                                   interpret=self.interpret))
-        # compact active lane rows into the dispatch width (pad rows are
-        # inactive: null tables, zero budget)
-        bt = np.full((w, self.max_blocks), NULL_BLOCK, np.int32)
-        lengths = np.zeros(w, np.int32)
-        remaining = np.zeros(w, np.int32)
-        tok = np.zeros(w, np.int32)
-        bt[:n_act] = self.block_tables[act]
-        lengths[:n_act] = self.lengths[act]
-        remaining[:n_act] = self.remaining[act]
-        tok[:n_act] = self.last_tok[act]
-        old_remaining = remaining.copy()
+        tr = get_tracer()
+        with tr.span("decode_prep", track=self.track):
+            w = next_pow2(n_act)
+            k_eff = self._scan_bucket(self.remaining[act])
+            fn = self._get_jitted(
+                "decode", (w, k_eff),
+                lambda: make_decode_fn(self.model, scan_tokens=k_eff,
+                                       interpret=self.interpret))
+            # compact active lane rows into the dispatch width (pad rows
+            # are inactive: null tables, zero budget)
+            bt = np.full((w, self.max_blocks), NULL_BLOCK, np.int32)
+            lengths = np.zeros(w, np.int32)
+            remaining = np.zeros(w, np.int32)
+            tok = np.zeros(w, np.int32)
+            bt[:n_act] = self.block_tables[act]
+            lengths[:n_act] = self.lengths[act]
+            remaining[:n_act] = self.remaining[act]
+            tok[:n_act] = self.last_tok[act]
+            old_remaining = remaining.copy()
 
-        with get_tracer().span("decode_scan", track=self.track, lanes=n_act,
-                               scan=k_eff), annotation(f"decode:{w}x{k_eff}"):
+        rids = [self.lanes[i].req.rid for i in act] if tr.enabled else None
+        with tr.span("decode_scan", track=self.track,
+                     label=f"decode:{w}x{k_eff}", lanes=n_act, scan=k_eff,
+                     rids=rids):
             self.pool, tok_o, lengths_o, remaining_o, toks = fn(
                 self.params, self.pool, jnp.asarray(tok[:, None]),
                 jnp.asarray(bt), jnp.asarray(lengths),
@@ -752,31 +778,34 @@ class PagedArmScheduler:
         act, n_act = pending["act"], pending["n_act"]
         k_eff = pending["k_eff"]
         old_remaining = pending["old_remaining"]
-        toks = np.asarray(pending["toks"])
-        tok_o = np.asarray(pending["tok_o"])
-        lengths_o = np.asarray(pending["lengths_o"])
-        remaining_o = np.asarray(pending["remaining_o"])
-
         tr = get_tracer()
+        with tr.span("decode_readback", track=self.track):
+            toks = np.asarray(pending["toks"])
+            tok_o = np.asarray(pending["tok_o"])
+            lengths_o = np.asarray(pending["lengths_o"])
+            remaining_o = np.asarray(pending["remaining_o"])
+
         retired: List[Lane] = []
-        for row, i in enumerate(act):
-            lane = pending["lanes"][row]
-            if self.lanes[i] is not lane:
-                # evicted mid-flight (ship backpressure): its tokens are
-                # discarded — the lane re-executes from prefill, and any
-                # stale device writes to its reallocated blocks were
-                # overwritten by later-enqueued work
-                continue
-            self.last_tok[i] = tok_o[row, 0]
-            self.lengths[i] = lengths_o[row]
-            self.remaining[i] = remaining_o[row]
-            n_take = min(int(old_remaining[row]), k_eff)
-            lane.out.extend(int(t) for t in toks[row, :n_take])
-            self.decoded_tokens += n_take
-            if self.remaining[i] == 0:
-                self._release(i, register=True)
-                retired.append(lane)
-                tr.instant("retire", track=self.track, req=lane.req.rid)
+        with tr.span("retire", track=self.track):
+            for row, i in enumerate(act):
+                lane = pending["lanes"][row]
+                if self.lanes[i] is not lane:
+                    # evicted mid-flight (ship backpressure): its tokens
+                    # are discarded — the lane re-executes from prefill,
+                    # and any stale device writes to its reallocated blocks
+                    # were overwritten by later-enqueued work
+                    continue
+                self.last_tok[i] = tok_o[row, 0]
+                self.lengths[i] = lengths_o[row]
+                self.remaining[i] = remaining_o[row]
+                n_take = min(int(old_remaining[row]), k_eff)
+                lane.out.extend(int(t) for t in toks[row, :n_take])
+                self.decoded_tokens += n_take
+                if self.remaining[i] == 0:
+                    self._release(i, register=True)
+                    retired.append(lane)
+                    tr.instant("retire", track=self.track,
+                               req=lane.req.rid)
         return retired
 
     # ------------------------------------------------------------- metrics
@@ -804,6 +833,8 @@ class PagedArmScheduler:
             "prefix_query_tokens": self.prefix_query_tokens,
             "prefix_hit_rate": round(
                 self.prefix_hit_tokens / max(self.prefix_query_tokens, 1), 4),
+            "prefill_wait_lane_s": self.prefill_wait_lane_s,
+            "prefill_busy_lane_s": self.prefill_busy_lane_s,
             "cow_copies": self.cow_copies,
             "preemptions": self.preemptions,
             "spilled_blocks": self.spilled_blocks,

@@ -80,17 +80,19 @@ class PlacementEngine:
     # ------------------------------------------------------------ execution
     def step(self) -> List[Outcome]:
         """One backend step; completed outcomes feed the policy and stats."""
-        outcomes = self.backend.step(self.policy)
         tr = get_tracer()
-        for o in outcomes:
-            if not (o.shed or o.failed):
-                # degradation terminals carry no execution signal — feeding
-                # them to the policy would punish arms for injected faults
-                self.policy.observe(o)
-            self.stats.record(o)
-            tr.instant("observe", req=o.request.rid,
-                       violated=bool(o.violated), shed=bool(o.shed),
-                       failed=bool(o.failed))
+        with tr.span("engine_step"):
+            outcomes = self.backend.step(self.policy)
+            for o in outcomes:
+                if not (o.shed or o.failed):
+                    # degradation terminals carry no execution signal —
+                    # feeding them to the policy would punish arms for
+                    # injected faults
+                    self.policy.observe(o)
+                self.stats.record(o)
+                tr.instant("observe", req=o.request.rid,
+                           violated=bool(o.violated), shed=bool(o.shed),
+                           failed=bool(o.failed))
         return outcomes
 
     def run(self, source=None, n_intervals: int = 100) -> dict:

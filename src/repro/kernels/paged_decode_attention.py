@@ -136,5 +136,6 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
         ),
         out_shape=jax.ShapeDtypeStruct((b, kh, rep, hd), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32), *args)
     return out.reshape(b, h, hd)

@@ -142,6 +142,7 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, positions, *,
         ),
         out_shape=jax.ShapeDtypeStruct((b, kh, r, hd), q.dtype),
         interpret=interpret,
+        name="paged_prefill_attention",
     )(jnp.max(positions, axis=1), block_tables.astype(jnp.int32), *args)
     return out.reshape(b, kh, c, rep, hd).transpose(0, 2, 1, 3, 4) \
         .reshape(b, c, h, hd)

@@ -2,12 +2,19 @@
 
 One process-global :class:`Tracer` (installed with :func:`set_tracer` or the
 :func:`trace_to` context manager) collects **spans** (nested timed regions:
-``with tracer.span("prefill_chunk", wave=4)``), **instants** (point events:
-``tracer.instant("retire", req=rid)``) and **counters** (monotonic series:
-``tracer.count("blocks_shipped", 8)``).  When tracing is off the global is
-the :data:`NULL_TRACER` singleton whose ``span``/``instant``/``count`` are
+``with tracer.span("prefill_chunk", wave=4)``) and **instants** (point
+events: ``tracer.instant("retire", req=rid)``).  When tracing is off the
+global is the :data:`NULL_TRACER` singleton whose ``span``/``instant`` are
 allocation-free no-ops — the serving hot path pays ~nothing (every traced
 region is per *dispatch*, never per token; the fused scans stay opaque).
+
+Spans also write into the JAX profiler.  With :func:`set_annotations` on,
+every span — of a :class:`Tracer` or of the :class:`NullTracer` — opens a
+``jax.profiler.TraceAnnotation`` named by its ``label=`` (the dispatch
+labels ``decode:WxK``, ``prefill:WxC``, ``cow:N``, ``ship:N``) or else
+``repro.<name>``, with its scalar attributes as the event's stats, so a
+``jax.profiler.start_trace`` capture shows the program's host phases on the
+same timeline as the device's programs.  Instants stay in the Tracer.
 
 Events carry a **track**: a ``(process, thread)`` label pair mapped to
 Chrome ``pid``/``tid`` at export, so a disaggregated run renders as parallel
@@ -17,7 +24,7 @@ inside an open span inherit the span's track, so scheduler-internal instants
 land on the right row without re-threading labels.
 
 :meth:`Tracer.export_chrome_trace` writes the standard trace-event JSON
-(``{"traceEvents": [...]}``, ``ph`` in ``X``/``i``/``C``/``M``, ``ts``/``dur``
+(``{"traceEvents": [...]}``, ``ph`` in ``X``/``i``/``M``, ``ts``/``dur``
 in microseconds) — load it at ``ui.perfetto.dev`` or ``chrome://tracing``.
 """
 from __future__ import annotations
@@ -33,8 +40,7 @@ Track = Union[str, Tuple[str, str]]
 
 
 class _NullSpan:
-    """Singleton no-op span/annotation context manager (also the disabled
-    stand-in for ``jax.profiler.TraceAnnotation``)."""
+    """Singleton no-op span context manager."""
 
     __slots__ = ()
 
@@ -58,13 +64,12 @@ class NullTracer:
     __slots__ = ()
     enabled = False
 
-    def span(self, name, *, track=None, **attrs):
+    def span(self, name, *, track=None, label=None, **attrs):
+        if _ANNOTATE:
+            return _profiler_event(name, label, attrs)
         return NULL_SPAN
 
     def instant(self, name, *, track=None, **attrs):
-        return None
-
-    def count(self, name, value=1, *, track=None):
         return None
 
     def export_chrome_trace(self, path):
@@ -75,26 +80,56 @@ class NullTracer:
 NULL_TRACER = NullTracer()
 
 
+def _scalars(attrs: dict) -> dict:
+    """The attributes a profiler event can carry as stats: its parser
+    mangles a list's ``"5,6"`` into ``5``, so lists stay in the Tracer."""
+    return {k: v for k, v in attrs.items()
+            if isinstance(v, (bool, int, float, str))}
+
+
+def _make_event_class():
+    from jax.profiler import TraceAnnotation
+
+    class _Annotation(TraceAnnotation):
+        """A profiler event that takes a span's mid-span ``set``."""
+
+        def set(self, **attrs):
+            self.set_metadata(**_scalars(attrs))
+            return self
+
+    return _Annotation
+
+
+def _profiler_event(name: str, label: Optional[str], attrs: dict):
+    return _EVENT_CLASS(label or f"repro.{name}", **_scalars(attrs))
+
+
 class _Span:
-    """One open timed region; records an ``X`` (complete) event on exit."""
+    """One open timed region; records an ``X`` (complete) event on exit,
+    and mirrors into the profiler while annotations are on."""
 
-    __slots__ = ("_tr", "name", "track", "args", "t0")
+    __slots__ = ("_tr", "name", "track", "args", "t0", "_ann")
 
-    def __init__(self, tr: "Tracer", name: str, track, args: dict):
+    def __init__(self, tr: "Tracer", name: str, track, args: dict, ann):
         self._tr = tr
         self.name = name
         self.track = track
         self.args = args
+        self._ann = ann
 
     def set(self, **attrs):
         """Attach attributes discovered mid-span (e.g. admitted counts)."""
         self.args.update(attrs)
+        if self._ann is not None:
+            self._ann.set(**attrs)
         return self
 
     def __enter__(self):
         tr = self._tr
         if self.track is None:
             self.track = tr._current_track()
+        if self._ann is not None:
+            self._ann.__enter__()
         self.t0 = tr._now()
         tr._stack.append(self)
         return self
@@ -105,6 +140,8 @@ class _Span:
         tr._stack.pop()
         tr._record(("X", self.name, self.track, self.t0,
                     t1 - self.t0, self.args))
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
@@ -132,7 +169,6 @@ class Tracer:
         # (ph, name, track, ts_us, dur_us, args) tuples
         self._events: List[tuple] = []
         self._stack: List[_Span] = []
-        self._counters: Dict[tuple, float] = {}
         self.stream_path = stream_path
         self._n_streamed = 0
         self._stream = None
@@ -172,10 +208,13 @@ class Tracer:
     def _current_track(self):
         return self._stack[-1].track if self._stack else ENGINE_TRACK
 
-    def span(self, name: str, *, track: Optional[Track] = None, **attrs):
+    def span(self, name: str, *, track: Optional[Track] = None,
+             label: Optional[str] = None, **attrs):
         """Open a nested timed region: ``with tracer.span("decode_scan",
-        track=..., lanes=4) as sp: ...; sp.set(retired=2)``."""
-        return _Span(self, name, track, attrs)
+        track=..., lanes=4) as sp: ...; sp.set(retired=2)``.  ``label``
+        names its profiler event (default ``repro.<name>``)."""
+        ann = _profiler_event(name, label, attrs) if _ANNOTATE else None
+        return _Span(self, name, track, attrs, ann)
 
     def instant(self, name: str, *, track: Optional[Track] = None, **attrs):
         """Point event (Perfetto arrow tick); inherits the open span's
@@ -183,17 +222,6 @@ class Tracer:
         if track is None:
             track = self._current_track()
         self._record(("i", name, track, self._now(), 0.0, attrs))
-
-    def count(self, name: str, value: float = 1, *,
-              track: Optional[Track] = None):
-        """Accumulate a monotonic counter series (Chrome ``C`` events plot
-        the running total per track)."""
-        if track is None:
-            track = self._current_track()
-        key = (name, _track_pair(track)[0])
-        total = self._counters.get(key, 0) + value
-        self._counters[key] = total
-        self._record(("C", name, track, self._now(), 0.0, {name: total}))
 
     @property
     def n_events(self) -> int:
@@ -278,6 +306,7 @@ def _jsonable(v):
 # -------------------------------------------------------- process globals
 _TRACER = NULL_TRACER
 _ANNOTATE = False
+_EVENT_CLASS = None  # the profiler event class, made on first use
 
 
 def get_tracer():
@@ -320,17 +349,11 @@ class trace_to:
 
 
 def set_annotations(on: bool) -> None:
-    """Toggle ``jax.profiler.TraceAnnotation`` wrapping of jitted
-    dispatches — device-timeline labels when profiling with
-    ``jax.profiler.start_trace`` (the benchmarks' ``--profile-dir``)."""
-    global _ANNOTATE
+    """Toggle the mirroring of every span into the JAX profiler as a
+    ``jax.profiler.TraceAnnotation`` (see the module docstring) — host
+    labels on the device timeline when profiling with
+    ``jax.profiler.start_trace``."""
+    global _ANNOTATE, _EVENT_CLASS
+    if on and _EVENT_CLASS is None:
+        _EVENT_CLASS = _make_event_class()
     _ANNOTATE = bool(on)
-
-
-def annotation(name: str):
-    """Context manager labelling the enclosed dispatch on the device
-    profile; the shared no-op singleton when annotations are off."""
-    if _ANNOTATE:
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation(name)
-    return NULL_SPAN

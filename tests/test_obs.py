@@ -55,12 +55,11 @@ def test_chrome_trace_schema_roundtrip(tmp_path):
         tr.instant("first_token", req=1)
     with tr.span("decode_scan", track=("arm0", "decode"), lanes=np.int64(4)):
         pass
-    tr.count("tokens", 16, track="arm0")
     path = tmp_path / "trace.json"
     tr.export_chrome_trace(str(path))
     doc = json.loads(path.read_text())
     evs = doc["traceEvents"]
-    assert evs and set(e["ph"] for e in evs) == {"M", "X", "i", "C"}
+    assert evs and set(e["ph"] for e in evs) == {"M", "X", "i"}
     for e in evs:
         assert isinstance(e["pid"], int) and isinstance(e["tid"], int)
         if e["ph"] != "M":
@@ -74,8 +73,7 @@ def test_chrome_trace_schema_roundtrip(tmp_path):
     threads = [e for e in evs if e["ph"] == "M"
                and e["name"] == "thread_name"]
     assert {p["args"]["name"] for p in procs} == {"arm0"}
-    assert {t["args"]["name"] for t in threads} == {"prefill", "decode",
-                                                    "main"}
+    assert {t["args"]["name"] for t in threads} == {"prefill", "decode"}
     # numpy attrs became plain JSON numbers
     (scan,) = [e for e in evs if e["name"] == "decode_scan"]
     assert scan["args"]["lanes"] == 4
@@ -106,19 +104,18 @@ def test_streaming_tracer_writes_incrementally(tmp_path):
     tr = Tracer(clock=lambda: next(clk), stream_path=str(path))
     with tr.span("prefill_chunk", track=("arm0", "prefill"), chunk=8):
         tr.instant("first_token", req=1)
-    tr.count("tokens", 16, track="arm0")
     # events went to disk, not the buffer; n_events still counts them
     assert tr.events() == []
-    assert tr.n_events == 3
+    assert tr.n_events == 2
     # mid-stream the file already holds the recorded events (valid after
     # appending the closing bracket — the incremental-write contract)
     doc = json.loads(path.read_text() + "]}")
-    assert {e["ph"] for e in doc["traceEvents"]} >= {"i", "C", "M"}
+    assert {e["ph"] for e in doc["traceEvents"]} >= {"i", "X", "M"}
     assert tr.export_chrome_trace("ignored") == str(path)
     assert tr.close() == str(path)            # idempotent
     doc = json.loads(path.read_text())
     evs = doc["traceEvents"]
-    assert {e["ph"] for e in evs} == {"M", "X", "i", "C"}
+    assert {e["ph"] for e in evs} == {"M", "X", "i"}
     (chunk,) = [e for e in evs if e["name"] == "prefill_chunk"]
     (ft,) = [e for e in evs if e["name"] == "first_token"]
     assert (ft["pid"], ft["tid"]) == (chunk["pid"], chunk["tid"])
@@ -137,12 +134,12 @@ def test_trace_to_streaming(tmp_path):
 
 
 def test_null_tracer_has_no_per_call_allocations():
-    """The disabled hot path: span()/instant()/count() return shared
-    singletons and allocate nothing, so per-dispatch instrumentation is
-    free when tracing is off."""
+    """The disabled hot path: span()/instant() return shared singletons
+    and allocate nothing, so per-dispatch instrumentation is free when
+    tracing is off."""
     tr = NULL_TRACER
     assert tr.span("a") is tr.span("b") is NULL_SPAN
-    assert tr.instant("x", req=1) is None and tr.count("c") is None
+    assert tr.instant("x", req=1) is None
     with tr.span("a", anything=1) as sp:
         assert sp.set(more=2) is NULL_SPAN
     # allocation guard: 10k traced-region entries on the disabled path must
@@ -159,6 +156,78 @@ def test_null_tracer_has_no_per_call_allocations():
     assert sys.getallocatedblocks() - base < 50
     with pytest.raises(RuntimeError, match="disabled"):
         tr.export_chrome_trace("/tmp/never.json")
+
+
+def _profile(tmp_path, body):
+    """Run ``body`` under a CPU profiler capture; the host plane's events
+    as {name: stats}."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for ev in line.events:
+                    out[ev.name] = {k: v for k, v in ev.stats}
+    return out
+
+
+def test_spans_mirror_into_the_profiler(tmp_path):
+    """With annotations on, a Tracer span also lands on the profiler's host
+    plane: under its label (or ``repro.<name>``), its scalar attributes and
+    those set mid-span as stats, its lists in the Tracer only."""
+    from repro.obs import set_annotations
+    tr = Tracer()
+
+    def body():
+        with tr.span("decode_scan", label="decode:4x8", lanes=3, scan=8,
+                     rids=[5, 6]) as sp:
+            with tr.span("decode_readback", frac=0.5, arm="layer"):
+                pass
+            sp.set(retired=1)
+
+    set_annotations(True)
+    try:
+        events = _profile(tmp_path, body)
+    finally:
+        set_annotations(False)
+    assert events["decode:4x8"] == {"lanes": 3, "scan": 8, "retired": 1}
+    assert events["repro.decode_readback"] == {"frac": 0.5, "arm": "layer"}
+    assert "decode_scan" not in events and "repro.decode_scan" not in events
+    (scan,) = tr.events("decode_scan")
+    assert scan[5] == {"lanes": 3, "scan": 8, "rids": [5, 6], "retired": 1}
+    assert tr.events("decode_readback")
+
+
+def test_null_tracer_mirrors_only_with_annotations_on(tmp_path):
+    """The NullTracer keeps the dispatch labels of a profiled run with no
+    Tracer installed, and stays the shared no-op with annotations off."""
+    from repro.obs import set_annotations
+    tr = NULL_TRACER
+
+    def body():
+        with tr.span("cow_copy", label="cow:4", pairs=3):
+            pass
+        with tr.span("join_wave", free=2) as sp:
+            sp.set(admitted=1)
+
+    set_annotations(True)
+    try:
+        assert tr.span("x") is not NULL_SPAN
+        events = _profile(tmp_path, body)
+    finally:
+        set_annotations(False)
+    assert events["cow:4"] == {"pairs": 3}
+    assert events["repro.join_wave"] == {"free": 2, "admitted": 1}
+    assert tr.span("x", label="cow:4") is NULL_SPAN
 
 
 # ------------------------------------------------------------- the histogram
