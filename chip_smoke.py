@@ -117,10 +117,13 @@ def clone(reqs):
 # ----------------------------------------------------------------- phases
 def kernel_parity(cfg, seed: int) -> None:
     """Compiled paged kernels vs their references at the served shapes:
-    LAYER (the full model's heads) and SEMANTIC (one branch's heads)."""
+    LAYER (the full model's heads) and SEMANTIC (one branch's heads).  The
+    decode kernel runs on a two-layer stacked pool in the decode step's
+    view, at layer 1; its pool writes must equal the reference's."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import ref
+    from repro.decode.paged_model import pages_view
     from repro.kernels.paged_decode_attention import paged_decode_attention
     from repro.kernels.paged_prefill_attention import paged_prefill_attention
 
@@ -134,24 +137,38 @@ def kernel_parity(cfg, seed: int) -> None:
         h, kh, hd = c.n_heads, c.n_kv_heads, c.hd
         bf = lambda *s: jnp.asarray(rng.normal(size=s), jnp.bfloat16)
         k_pool, v_pool = bf(n_blocks, bs, kh, hd), bf(n_blocks, bs, kh, hd)
-        tables = jnp.asarray(1 + rng.permutation(n_blocks - 1)
-                             .reshape(MAX_BATCH, nb), jnp.int32)
-        lengths = jnp.asarray(rng.integers(1, CACHE_LEN + 1, MAX_BATCH),
-                              jnp.int32)
+        table_np = (1 + rng.permutation(n_blocks - 1)).reshape(MAX_BATCH, nb)
+        tables = jnp.asarray(table_np, jnp.int32)
+        lens_np = rng.integers(1, CACHE_LEN + 1, MAX_BATCH)
+        lengths = jnp.asarray(lens_np, jnp.int32)
         starts = rng.integers(0, CACHE_LEN - chunk + 1, MAX_BATCH)
         positions = jnp.asarray(starts[:, None] + np.arange(chunk), jnp.int32)
+        last = lens_np - 1
+        stacked = pages_view({"k": jnp.stack([k_pool, bf(*k_pool.shape)]),
+                              "v": jnp.stack([v_pool, bf(*v_pool.shape)])})
+        new = {"k": bf(MAX_BATCH, kh, hd), "v": bf(MAX_BATCH, kh, hd)}
+        decode_args = (bf(MAX_BATCH, h, hd), new, stacked, jnp.int32(1),
+                       tables, lengths,
+                       jnp.asarray(table_np[np.arange(MAX_BATCH), last // bs],
+                                   jnp.int32),
+                       jnp.asarray(last % bs, jnp.int32))
         cases = (
             ("paged_decode_attention", paged_decode_attention,
-             ref.paged_decode_attention_ref,
-             (bf(MAX_BATCH, h, hd), k_pool, v_pool, tables, lengths)),
+             ref.paged_decode_attention_ref, decode_args),
             ("paged_prefill_attention", paged_prefill_attention,
              ref.paged_prefill_attention_ref,
              (bf(MAX_BATCH, chunk, h, hd), k_pool, v_pool, tables,
               positions)),
         )
         for name, kernel, reference, args in cases:
-            out = np.asarray(jax.jit(kernel)(*args), np.float32)
-            exp = np.asarray(jax.jit(reference)(*args), np.float32)
+            out, exp = jax.jit(kernel)(*args), jax.jit(reference)(*args)
+            if isinstance(out, tuple):
+                (out, pool), (exp, exp_pool) = out, exp
+                for key in pool:
+                    check(bool(jnp.array_equal(pool[key], exp_pool[key])),
+                          f"{name} ({arm}) wrote {key} unlike its reference")
+            out = np.asarray(out, np.float32)
+            exp = np.asarray(exp, np.float32)
             err = float(np.max(np.abs(out - exp)))
             print(f"kernel {name} [{arm}: H={h} K={kh} hd={hd} "
                   f"pool={n_blocks}x{bs}]: max_abs_err={err:.3e}",

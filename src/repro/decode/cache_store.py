@@ -44,7 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.decode.paged_cache import (NULL_BLOCK, _is_scale_path,
+from repro.decode.paged_cache import (NULL_BLOCK, is_scale_path,
                                       gather_blocks, scatter_blocks)
 from repro.decode.scheduler import Lane, PagedArmScheduler
 from repro.engine.types import next_pow2
@@ -496,7 +496,7 @@ class CacheStore:
 
     # ------------------------------------------------------ fleet (2 dev)
     def _block_axis(self, path, x) -> int:
-        return x.ndim - (3 if _is_scale_path(path) else 4)
+        return x.ndim - (3 if is_scale_path(path) else 4)
 
     def _fleet_init(self) -> None:
         self._mesh = Mesh(np.array([self.src.device, self.dst.device]),

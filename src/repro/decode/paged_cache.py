@@ -376,7 +376,9 @@ def int8_kv_capacity_ratio(head_dim: int, scale_bytes: int = 4) -> float:
     return (4.0 * head_dim) / (head_dim + scale_bytes)
 
 
-def _is_scale_path(path) -> bool:
+def is_scale_path(path) -> bool:
+    """Whether a pool leaf's tree path names an int8 scale leaf
+    (``"k_scale"``/``"v_scale"``), as opposed to a K/V leaf."""
     last = path[-1]
     name = getattr(last, "key", None)
     return isinstance(name, str) and name.endswith("_scale")
@@ -388,7 +390,7 @@ def pool_block_bytes(pool) -> int:
     ([..., P, bs, K]) have their physical axis at -3, KV leaves at -4."""
     total = 0
     for path, leaf in jax.tree_util.tree_flatten_with_path(pool)[0]:
-        p = leaf.shape[-3] if _is_scale_path(path) else leaf.shape[-4]
+        p = leaf.shape[-3] if is_scale_path(path) else leaf.shape[-4]
         total += (leaf.size // p) * leaf.dtype.itemsize
     return total
 
@@ -401,7 +403,7 @@ def copy_blocks(pool, src: jax.Array, dst: jax.Array):
     scale leaves ([..., P, bs, K], physical axis -3) ride along, so a COW'd
     quantized block never requantizes."""
     def leaf(path, x):
-        if _is_scale_path(path):
+        if is_scale_path(path):
             return x.at[..., dst, :, :].set(x[..., src, :, :])
         return x.at[..., dst, :, :, :].set(x[..., src, :, :, :])
 
@@ -416,7 +418,7 @@ def gather_blocks(pool, ids: jax.Array):
     and their per-token-slot ``_scale`` leaves are extracted verbatim, so a
     shipped quantized block is never requantized in flight."""
     def leaf(path, x):
-        if _is_scale_path(path):
+        if is_scale_path(path):
             return x[..., ids, :, :]
         return x[..., ids, :, :, :]
 
@@ -429,7 +431,7 @@ def scatter_blocks(pool, payload, ids: jax.Array):
     at the null scratch block (whose contents are garbage by design), so one
     unconditional scatter serves any pow2-bucketed wave width."""
     def leaf(path, x, p):
-        if _is_scale_path(path):
+        if is_scale_path(path):
             return x.at[..., ids, :, :].set(p)
         return x.at[..., ids, :, :, :].set(p)
 

@@ -25,9 +25,12 @@ Three jit-friendly builders over a ``repro.models`` model (single-branch
 Both paged attention paths dispatch to their Pallas kernels
 (``paged_decode_attention``, ``paged_prefill_attention``) on TPU backends
 and to the dense-gather XLA references elsewhere — the same dispatch
-convention as ``repro.models.attention``.  Block tables may alias physical
-blocks across lanes (prefix sharing); the attention paths only ever gather
-through the table, so aliasing is read-only.
+convention as ``repro.models.attention``.  The decode step hands its kernel
+the whole stacked pool and the layer index, and the kernel writes the new
+token and reads each lane's pages where they lie; the prefill chunk scans
+per-layer pool entries.  Block tables may alias physical blocks across
+lanes (prefix sharing); the attention paths only ever gather through the
+table, and a lane writes only its own blocks, so aliasing is read-only.
 
 Quantized serving rides the same forwards: an int8 pool (``"k_scale"`` /
 ``"v_scale"`` leaves — see ``paged_cache.quantize_pool``) makes every
@@ -43,10 +46,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.decode.paged_cache import (chunk_write_slots, quantize_kv,
-                                      write_slots)
+from repro.decode.paged_cache import (is_scale_path, chunk_write_slots,
+                                      quantize_kv, write_slots)
 from repro.kernels import ref
-from repro.kernels.paged_decode_attention import paged_decode_attention
+from repro.kernels.paged_decode_attention import (paged_decode_attention,
+                                                  slot_shape)
 from repro.kernels.paged_prefill_attention import paged_prefill_attention
 from repro.kernels.quant_matmul import (dequantize_blockwise, infer_bits,
                                         quant_matmul, quantize_blockwise)
@@ -121,16 +125,13 @@ def _proj(x, w, interpret: bool):
     return out.reshape(b, s, -1)
 
 
-def _attend(q, k_pool, v_pool, block_tables, valid_lens, softcap,
-            interpret: bool, k_scale=None, v_scale=None):
+def _attend(q, new, pool, layer, block_tables, valid_lens, wb, wo, softcap,
+            interpret: bool):
+    args = (q, new, pool, layer, block_tables, valid_lens, wb, wo)
     if interpret or jax.default_backend() == "tpu":
-        return paged_decode_attention(q, k_pool, v_pool, block_tables,
-                                      valid_lens, k_scale=k_scale,
-                                      v_scale=v_scale, softcap=softcap,
+        return paged_decode_attention(*args, softcap=softcap,
                                       interpret=interpret)
-    return ref.paged_decode_attention_ref(q, k_pool, v_pool, block_tables,
-                                          valid_lens, k_scale=k_scale,
-                                          v_scale=v_scale, softcap=softcap)
+    return ref.paged_decode_attention_ref(*args, softcap=softcap)
 
 
 def _chunk_attend(q, k_pool, v_pool, block_tables, positions, softcap,
@@ -145,28 +146,43 @@ def _chunk_attend(q, k_pool, v_pool, block_tables, positions, softcap,
                                            v_scale=v_scale, softcap=softcap)
 
 
-def _scatter_kv(pool, k, v, wb, wo):
-    """Scatter new K/V into their (wb, wo) slots, quantizing on write when
-    the pool carries int8 code + scale leaves.  Per-token scales mean each
+def pages_view(pool):
+    """The pool as the decode kernel reads it: each K/V leaf
+    ``[..., K, hd]`` reshaped to ``[..., *slot_shape(K, hd, dtype)]``
+    (128-lane rows of heads side by side when hd < 128); scale leaves as
+    they are.  The same values in the same order, so a reshape back undoes
+    it."""
+    def leaf(path, x):
+        if is_scale_path(path):
+            return x
+        return x.reshape(x.shape[:-2] + slot_shape(*x.shape[-2:], x.dtype))
+    return jax.tree_util.tree_map_with_path(leaf, pool)
+
+
+def _kv_slots(pool, k, v):
+    """The pool leaves' contents for new K/V, quantized on write when the
+    pool carries int8 code + scale leaves.  Per-token scales mean each
     written slot depends only on its own K/V vector — chunk prefill, decode
     steps and COW copies all commit identical bytes for identical tokens."""
     if "k_scale" in pool:
         kq, ks = quantize_kv(k)
         vq, vs = quantize_kv(v)
-        return {
-            "k": pool["k"].at[wb, wo].set(kq),
-            "k_scale": pool["k_scale"].at[wb, wo].set(ks),
-            "v": pool["v"].at[wb, wo].set(vq),
-            "v_scale": pool["v_scale"].at[wb, wo].set(vs),
-        }
-    return {"k": pool["k"].at[wb, wo].set(k.astype(pool["k"].dtype)),
-            "v": pool["v"].at[wb, wo].set(v.astype(pool["v"].dtype))}
+        return {"k": kq, "k_scale": ks, "v": vq, "v_scale": vs}
+    return {"k": k.astype(pool["k"].dtype), "v": v.astype(pool["v"].dtype)}
 
 
-def _paged_attn(params, x, cfg: ArchConfig, *, positions, pool, block_tables,
-                valid_lens, wb, wo, interpret: bool):
-    """One-token GQA attention against the paged pool: scatter the new K/V
-    into (wb, wo) write slots, then attend through the block table."""
+def _scatter_kv(pool, k, v, wb, wo):
+    """Scatter new K/V into their (wb, wo) slots of one layer's pool."""
+    return {key: pool[key].at[wb, wo].set(x)
+            for key, x in _kv_slots(pool, k, v).items()}
+
+
+def _paged_attn(params, x, cfg: ArchConfig, *, positions, pool, layer,
+                block_tables, valid_lens, wb, wo, interpret: bool):
+    """One-token GQA attention against layer ``layer`` of the stacked paged
+    pool: the new K/V go to their (wb, wo) write slots and the query
+    attends through the block table, in one kernel call that updates the
+    pool in place."""
     b, s, _ = x.shape                       # s == 1
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     q = _proj(x, params["wq"], interpret).reshape(b, s, h, hd)
@@ -174,10 +190,9 @@ def _paged_attn(params, x, cfg: ArchConfig, *, positions, pool, block_tables,
     v = _proj(x, params["wv"], interpret).reshape(b, s, kv, hd)
     q = L.apply_rope(q, positions, cfg.rope_theta)
     k = L.apply_rope(k, positions, cfg.rope_theta)
-    npool = _scatter_kv(pool, k[:, 0], v[:, 0], wb, wo)
-    out = _attend(q[:, 0], npool["k"], npool["v"], block_tables, valid_lens,
-                  cfg.attn_softcap, interpret,
-                  k_scale=npool.get("k_scale"), v_scale=npool.get("v_scale"))
+    out, npool = _attend(q[:, 0], _kv_slots(pool, k[:, 0], v[:, 0]), pool,
+                         layer, block_tables, valid_lens, wb, wo,
+                         cfg.attn_softcap, interpret)
     out = _proj(out.reshape(b, s, h * hd), params["wo"], interpret)
     return out, npool
 
@@ -230,8 +245,10 @@ def _stack_body(cfg: ArchConfig, h, sb_params, sb_pool, attn_fn):
 
 def _scan_stack(cfg: ArchConfig, params, pool, x, attn_fn):
     """Scan the superblock stack with the pool in the loop carry: each
-    superblock reads its pool entry and writes it back in place
-    (``dynamic_update_index_in_dim``).  Passing the pool as scanned
+    superblock reads its pool entry and writes it back
+    (``dynamic_update_index_in_dim``; on the chip XLA copies the entry out
+    and back around the attention, which the decode step's
+    :func:`_decode_stack` avoids).  Passing the pool as scanned
     inputs/outputs instead makes XLA hold a second full pool for the
     stacked outputs, which at published widths does not fit one chip."""
     def body(carry, xs):
@@ -244,6 +261,25 @@ def _scan_stack(cfg: ArchConfig, params, pool, x, attn_fn):
         pool = jax.tree.map(
             lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, i, 0),
             pool, new_sb_pool)
+        return (h, pool), None
+
+    n_sb = jax.tree.leaves(pool)[0].shape[0]
+    (x, pool), _ = jax.lax.scan(body, (x, pool),
+                                (params["blocks"], jnp.arange(n_sb)))
+    return x, pool
+
+
+def _decode_stack(cfg: ArchConfig, params, pool, x, attn_fn):
+    """Scan the superblock stack for one decode token with the whole
+    stacked pool in the loop carry: ``attn_fn(blk_params, hn, entry, i)``
+    gets superblock i's stacked pool entry and the index, and its kernel
+    reads and writes layer i where it lies, so no pool leaf is sliced out
+    or written back per layer."""
+    def body(carry, xs):
+        h, pool = carry
+        sb_params, i = xs
+        h, pool = _stack_body(cfg, h, sb_params, pool,
+                              lambda p, hn, entry: attn_fn(p, hn, entry, i))
         return (h, pool), None
 
     n_sb = jax.tree.leaves(pool)[0].shape[0]
@@ -265,11 +301,11 @@ def _paged_step_one(model: Model, params, pool, tokens, block_tables,
     wb, wo = write_slots(lengths, block_tables, active, block_size)
     valid_lens = lengths + active.astype(jnp.int32)
 
-    attn = lambda p, hn, entry: _paged_attn(
-        p, hn, cfg, positions=positions, pool=entry,
+    attn = lambda p, hn, entry, i: _paged_attn(
+        p, hn, cfg, positions=positions, pool=entry, layer=i,
         block_tables=block_tables, valid_lens=valid_lens, wb=wb, wo=wo,
         interpret=interpret)
-    x, new_pool = _scan_stack(cfg, params, pool, x, attn)
+    x, new_pool = _decode_stack(cfg, params, pool, x, attn)
     x = L.norm_apply(params["final_norm"], x, cfg)
     logits = L.unembed_apply(params["embed"], x, cfg)
     return logits[:, -1], new_pool
@@ -303,7 +339,9 @@ def _paged_chunk_one(model: Model, params, pool, tokens, starts, n_tok,
 def paged_decode_logits(model, params, pool, tokens, block_tables, lengths,
                         active, *, interpret: bool = False):
     """One paged decode step for either model flavor.  Semantic models vmap
-    the branch step over (params, pool) and merge the vocab shards."""
+    the branch step over (params, pool) and merge the vocab shards.  The
+    pool is in :func:`pages_view`, the kernel's view of its K/V leaves, and
+    comes back in it."""
     if isinstance(model, SemanticModel):
         step = lambda p, c: _paged_step_one(
             model.branch, p, c, tokens, block_tables, lengths, active,
@@ -357,6 +395,8 @@ def make_decode_fn(model, *, scan_tokens: int, interpret: bool = False):
     """
 
     def decode(params, pool, tok, block_tables, lengths, remaining):
+        stored = pool
+
         def step(carry, _):
             pool, tok, lengths, remaining = carry
             active = remaining > 0
@@ -369,9 +409,13 @@ def make_decode_fn(model, *, scan_tokens: int, interpret: bool = False):
             remaining = remaining - active.astype(jnp.int32)
             return (pool, tok, lengths, remaining), nxt
 
+        # the kernel's view for the whole dispatch, so the layer and token
+        # loops carry one pool that the kernel updates in place
         carry, toks = jax.lax.scan(
-            step, (pool, tok, lengths, remaining), length=scan_tokens)
+            step, (pages_view(pool), tok, lengths, remaining),
+            length=scan_tokens)
         pool, tok, lengths, remaining = carry
+        pool = jax.tree.map(lambda x, s: x.reshape(s.shape), pool, stored)
         return pool, tok, lengths, remaining, toks.T
 
     return decode

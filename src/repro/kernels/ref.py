@@ -6,6 +6,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.paged_decode_attention import slot_shape
+
 
 def flash_attention_ref(q, k, v, *, causal=True, window=0, softcap=0.0):
     b, sq, h, hd = q.shape
@@ -90,24 +92,36 @@ def dequant_pool_ref(pool, scale):
     return pool.astype(jnp.float32) * scale[..., None]
 
 
-def paged_decode_attention_ref(q, k_pool, v_pool, block_tables, lengths, *,
-                               k_scale=None, v_scale=None, softcap=0.0):
-    """Dense-gather oracle for the paged decode kernel.
-
-    q: [B, H, hd]; k/v_pool: [P, bs, K, hd] physical block pools;
-    block_tables: [B, NB] int32 (entry 0 = reserved null block);
-    lengths: [B] valid token count per sequence.  Gathers each sequence's
-    blocks into a dense [B, NB*bs, K, hd] cache and defers to
-    ``decode_attention_ref``.  Tables of different sequences may alias the
-    same physical blocks (prefix sharing) — the gather is read-only.
-    ``k_scale``/``v_scale`` [P, bs, K] dequantize int8 pools first.
-    """
-    k = dequant_pool_ref(k_pool, k_scale)[block_tables]  # [B, NB, bs, K, hd]
-    v = dequant_pool_ref(v_pool, v_scale)[block_tables]
-    b, nb, bs, kh, hd = k.shape
-    k = k.reshape(b, nb * bs, kh, hd)
-    v = v.reshape(b, nb * bs, kh, hd)
-    return decode_attention_ref(q, k, v, lengths, softcap=softcap)
+def paged_decode_attention_ref(q, new, pool, layer, block_tables, lengths,
+                               wb, wo, *, softcap=0.0):
+    """Scatter-then-gather oracle for the paged decode kernel, on the same
+    contract: the stacked pool leaves ``pool`` ("k"/"v" [N, P, bs,
+    *slot_shape(K, hd, dtype)], the kernel's view; int8 pools with
+    "k_scale"/"v_scale" [N, P, bs, K]) take the step's rows ``new`` (K/V
+    [B, K, hd], scales [B, K]) at ``[layer, wb, wo]``, then q [B, H, hd]
+    attends layer ``layer``'s pages through block_tables [B, NB] (entry 0 =
+    reserved null block) over lengths [B] valid tokens, the new one
+    included: each lane's blocks gather into a dense cache and
+    ``decode_attention_ref`` decides.  Tables of different lanes may alias
+    the same pages (prefix sharing).  A lane of length 0 returns zeros.
+    Returns (out [B, H, hd], pool)."""
+    b, kh, hd = new["k"].shape
+    assert pool["k"].shape[3:] == slot_shape(kh, hd, pool["k"].dtype)
+    pool = {key: leaf.at[layer, wb, wo].set(
+                new[key].astype(leaf.dtype).reshape((b,) + leaf.shape[3:]))
+            for key, leaf in pool.items()}
+    kv = {key: pool[key][layer] for key in ("k", "v")}
+    kv = {key: x.reshape(x.shape[:2] + (kh, hd)) for key, x in kv.items()}
+    scale = {key: pool[key][layer] if key in pool else None
+             for key in ("k_scale", "v_scale")}
+    k = dequant_pool_ref(kv["k"], scale["k_scale"])
+    v = dequant_pool_ref(kv["v"], scale["v_scale"])
+    k, v = k[block_tables], v[block_tables]           # [B, NB, bs, K, hd]
+    n = block_tables.shape[1] * k.shape[2]
+    out = decode_attention_ref(q, k.reshape(b, n, kh, hd),
+                               v.reshape(b, n, kh, hd), lengths,
+                               softcap=softcap)
+    return jnp.where(lengths[:, None, None] > 0, out, 0).astype(q.dtype), pool
 
 
 def paged_prefill_attention_ref(q, k_pool, v_pool, block_tables, positions, *,

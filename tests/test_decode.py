@@ -35,61 +35,105 @@ from repro.decode import (BlockAllocator, NULL_BLOCK, PagedArmScheduler,
 from repro.engine import (LAYER, SEMANTIC, FixedPolicy, MABPolicy,
                           PlacementEngine, Request)
 from repro.engine.jax_backend import JaxBackend
-from repro.kernels import ref
-from repro.kernels.paged_decode_attention import paged_decode_attention
+from paged_decode_cases import check, make_case, run_kernel, run_ref
 
 RNG = np.random.default_rng(0)
 
 
 # ------------------------------------------------------------------- kernel
-@pytest.mark.parametrize("h,kh,hd", [(4, 4, 32), (8, 2, 64)])
-@pytest.mark.parametrize("bs,nb", [(4, 4), (8, 2)])
-def test_paged_kernel_matches_dense_reference(h, kh, hd, bs, nb):
-    """Gathering K/V through the block table (interpret mode) matches a
-    contiguous dense decode-attention reference to <= 1e-3."""
-    b = 3
-    p_blocks = 1 + b * nb
-    q = jnp.asarray(RNG.normal(size=(b, h, hd)), jnp.float32)
-    kp = jnp.asarray(RNG.normal(size=(p_blocks, bs, kh, hd)), jnp.float32)
-    vp = jnp.asarray(RNG.normal(size=(p_blocks, bs, kh, hd)), jnp.float32)
-    # shuffled physical blocks: paged layout is deliberately non-contiguous
-    perm = RNG.permutation(np.arange(1, p_blocks))
-    bt = perm.reshape(b, nb).astype(np.int32)
-    lengths = jnp.asarray(RNG.integers(1, nb * bs + 1, b), jnp.int32)
+def _edge_lengths(bs, nb):
+    """Lane lengths at the page edges: empty, one token, a page less one,
+    a full page, a page and one, a full lane."""
+    return [0, 1, bs - 1, bs, bs + 1, nb * bs]
 
-    out = paged_decode_attention(q, kp, vp, jnp.asarray(bt), lengths,
-                                 interpret=True)
-    # dense reference: materialize each sequence's cache contiguously
-    k_dense = kp[bt].reshape(b, nb * bs, kh, hd)
-    v_dense = vp[bt].reshape(b, nb * bs, kh, hd)
-    exp = ref.decode_attention_ref(q, k_dense, v_dense, lengths)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=1e-3,
-                               rtol=1e-3)
-    # and the paged oracle agrees with itself
-    exp2 = ref.paged_decode_attention_ref(q, kp, vp, jnp.asarray(bt), lengths)
-    np.testing.assert_allclose(np.asarray(exp), np.asarray(exp2), atol=1e-6)
+
+@pytest.mark.parametrize("h,kh,hd", [(4, 4, 32), (8, 2, 64), (4, 4, 64),
+                                     (24, 2, 128)])
+@pytest.mark.parametrize("bs,nb", [(4, 4), (8, 2), (16, 2)])
+def test_paged_kernel_matches_dense_reference(h, kh, hd, bs, nb):
+    """The kernel (interpret mode) on a stacked f32 pool, at a layer other
+    than 0: the new token lands at its write slot and nowhere else, and the
+    attention through the block table, new token included, matches a dense
+    decode-attention reference over the updated pool to <= 1e-3; a lane of
+    length 0 (inactive, writing the null block) returns zeros.  MHA and
+    GQA up to 12 queries a kv head; the paged oracle holds to the same
+    contract."""
+    case = make_case(RNG, h, kh, hd, bs, nb, _edge_lengths(bs, nb))
+    check(case, *run_kernel(case))
+    check(case, *run_ref(case), atol=1e-6, rtol=1e-7)
+
+
+@pytest.mark.parametrize("h,kh,hd", [(32, 32, 64), (48, 4, 128),
+                                     (12, 1, 128)],
+                         ids=["mha-hd64", "gqa-rep12-hd128", "one-kv-head"])
+def test_paged_kernel_bf16_matches_dense_reference(h, kh, hd):
+    """bf16 pools at the served widths' head shapes (stablelm-1.6b's MHA at
+    hd 64, starcoder2-15b's 12 queries per kv head at hd 128, and a single
+    kv head, whose slot is one row) and the served block size: the pages
+    are read in bf16 and attended in f32, so only the bf16 output's
+    rounding separates the kernel from the f32 reference."""
+    case = make_case(RNG, h, kh, hd, 16, 2, _edge_lengths(16, 2),
+                     dtype=jnp.bfloat16)
+    check(case, *run_kernel(case), atol=1e-2)
 
 
 def test_paged_kernel_aliased_block_tables():
-    """Prefix sharing makes lanes ALIAS physical blocks: the gather must
-    stay correct when several tables point at the same block (read-only
-    aliasing — the kernel never writes the pool)."""
-    h, kh, hd, bs, nb, b = 4, 2, 32, 4, 3, 3
-    p_blocks = 1 + 4
-    q = jnp.asarray(RNG.normal(size=(b, h, hd)), jnp.float32)
-    kp = jnp.asarray(RNG.normal(size=(p_blocks, bs, kh, hd)), jnp.float32)
-    vp = jnp.asarray(RNG.normal(size=(p_blocks, bs, kh, hd)), jnp.float32)
-    # every lane shares blocks 1,2 (a common prompt head) + its own tail
-    bt = np.asarray([[1, 2, 3], [1, 2, 4], [1, 2, 3]], np.int32)
-    lengths = jnp.asarray([12, 10, 9], jnp.int32)
+    """Prefix sharing makes lanes ALIAS physical blocks: every lane's
+    table starts with the same two blocks (a common prompt head) and the
+    gather stays correct; each active lane writes only its own tail block,
+    so the shared blocks, and an inactive lane's pages, stay bit-identical
+    while the inactive lane writes the null block."""
+    for h, kh, hd in ((4, 2, 32), (4, 4, 64)):
+        case = make_case(RNG, h, kh, hd, 4, 4, [12, 10, 9, 11], alias=2,
+                         active=[True, True, True, False])
+        check(case, *run_kernel(case))
 
-    out = paged_decode_attention(q, kp, vp, jnp.asarray(bt), lengths,
-                                 interpret=True)
-    k_dense = kp[bt].reshape(b, nb * bs, kh, hd)
-    v_dense = vp[bt].reshape(b, nb * bs, kh, hd)
-    exp = ref.decode_attention_ref(q, k_dense, v_dense, lengths)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=1e-3,
-                               rtol=1e-3)
+
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+def test_paged_kernel_vmapped_branches(int8):
+    """The SEMANTIC arm vmaps the decode step over its branches: the branch
+    axis folds into the kernel's grid and pool index, and each branch
+    matches its own dense reference and write slots."""
+    case = make_case(RNG, 24, 2, 128, 16, 4, [17, 64, 15, 0], int8=int8,
+                     branches=2)
+    check(case, *run_kernel(case))
+
+
+@pytest.mark.parametrize("arm", ["layer", "semantic"])
+def test_paged_decode_step_kernel_matches_reference(tiny_cfg, arm):
+    """A whole paged decode step through the kernel (interpret mode) on the
+    lane-dense stacked pool matches the same step through the XLA
+    reference: the LAYER model, and the SEMANTIC model whose step vmaps
+    over its branches; an inactive lane rides along."""
+    from repro.decode.paged_model import pages_view, paged_decode_logits
+    from repro.models.model import Model, SemanticModel
+    cfg = tiny_cfg.replace(n_heads=4, n_kv_heads=4, head_dim=32)
+    model = Model(cfg) if arm == "layer" else SemanticModel(cfg.semantic(2))
+    params = model.init(jax.random.PRNGKey(0))
+    b, bs, nb = 3, 4, 4
+    pool = pages_view(jax.tree.map(
+        lambda x: jnp.asarray(RNG.normal(size=x.shape), x.dtype),
+        model.init_cache(1 + b * nb, bs)))
+    tables = jnp.asarray(RNG.permutation(np.arange(1, 1 + b * nb))
+                         .reshape(b, nb), jnp.int32)
+    lengths = jnp.asarray([5, 12, 3], jnp.int32)
+    active = jnp.asarray([True, True, False])
+    tokens = jnp.asarray(RNG.integers(0, cfg.vocab_size, (b, 1)), jnp.int32)
+    out = {}
+    for interpret in (True, False):
+        step = jax.jit(lambda p, c, interpret=interpret: paged_decode_logits(
+            model, p, c, tokens, tables, lengths, active,
+            interpret=interpret))
+        out[interpret] = step(params, pool)
+    (got, got_pool), (exp, exp_pool) = out[True], out[False]
+    np.testing.assert_allclose(np.asarray(got)[:2], np.asarray(exp)[:2],
+                               atol=1e-4, rtol=1e-4)
+    for key in exp_pool["pos0"]:
+        # block 0 is the inactive lane's scratch
+        np.testing.assert_allclose(
+            np.asarray(got_pool["pos0"][key])[..., 1:, :, :, :],
+            np.asarray(exp_pool["pos0"][key])[..., 1:, :, :, :],
+            atol=1e-4, rtol=1e-4, err_msg=key)
 
 
 # ---------------------------------------------------------------- allocator

@@ -32,10 +32,11 @@ from repro.decode import (PagedArmScheduler, copy_blocks,
                           quantize_kv, quantize_pool)
 from repro.engine import Request
 from repro.kernels import ref
-from repro.kernels.paged_decode_attention import paged_decode_attention
 from repro.kernels.paged_prefill_attention import paged_prefill_attention
 from repro.kernels.quant_matmul import (dequantize_blockwise, quant_matmul,
                                         quantize_blockwise)
+
+from paged_decode_cases import check, make_case, run_kernel, run_ref
 
 RNG = np.random.default_rng(0)
 
@@ -147,24 +148,24 @@ def test_prefill_kernel_matches_ref(h, kh, hd, kv_dtype):
 
 
 def test_decode_kernel_int8_matches_ref():
-    """The paged decode kernel's int8 dequant epilogue matches the
-    reference's dequantize-then-gather."""
-    b, h, kh, hd, bs, nb = 3, 4, 2, 32, 4, 4
-    p_blocks = 1 + b * nb
-    q = jnp.asarray(RNG.normal(size=(b, h, hd)), jnp.float32)
-    kq, ks = quantize_kv(
-        jnp.asarray(RNG.normal(size=(p_blocks, bs, kh, hd)), jnp.float32))
-    vq, vs = quantize_kv(
-        jnp.asarray(RNG.normal(size=(p_blocks, bs, kh, hd)), jnp.float32))
-    perm = RNG.permutation(np.arange(1, p_blocks))
-    bt = jnp.asarray(perm.reshape(b, nb).astype(np.int32))
-    lengths = jnp.asarray(RNG.integers(1, nb * bs + 1, b), jnp.int32)
-    out = paged_decode_attention(q, kq, vq, bt, lengths, k_scale=ks,
-                                 v_scale=vs, interpret=True)
-    exp = ref.paged_decode_attention_ref(q, kq, vq, bt, lengths, k_scale=ks,
-                                         v_scale=vs)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=1e-3,
-                               rtol=1e-3)
+    """The paged decode kernel's int8 dequant matches the reference's
+    dequantize-then-gather, and the new token's codes and per-slot scales
+    land at its write slot and nowhere else."""
+    case = make_case(RNG, 4, 2, 32, 4, 4, [7, 16, 1, 0], int8=True)
+    check(case, *run_kernel(case))
+    check(case, *run_ref(case), atol=1e-6, rtol=1e-7)
+
+
+@pytest.mark.parametrize("h,kh,hd,alias", [(8, 8, 64, 0), (24, 2, 128, 1)],
+                         ids=["mha-hd64", "gqa-rep12-hd128-aliased"])
+def test_decode_kernel_int8_served_head_shapes(h, kh, hd, alias):
+    """int8 pools at the served head shapes: MHA at hd 64 (two heads to a
+    128-lane row, each dequantized by its own scale) and 12 queries per kv
+    head at hd 128 over a shared prefix block (active lanes write past
+    it); lengths at the page edges."""
+    case = make_case(RNG, h, kh, hd, 16, 3, [17, 32, 33, 48, 0],
+                     int8=True, alias=alias)
+    check(case, *run_kernel(case))
 
 
 # ------------------------------------------------------ int8 block layout
